@@ -7,7 +7,8 @@ Prints ``name,us_per_call,derived`` CSV.  Usage:
 ``--smoke`` runs every module at tiny budgets (CI perf-trajectory mode);
 ``--json PATH`` additionally writes the rows as a JSON list of
 ``{bench, name, us_per_call, derived}`` objects (the CI artifact
-``BENCH_pr.json``).
+``BENCH_pr.json``).  Without ``--only``, ``shard_scaling`` runs only when
+JAX sees several devices.
 """
 from __future__ import annotations
 
@@ -17,6 +18,10 @@ import inspect
 import json
 import sys
 import traceback
+
+import jax
+
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     "pipeline_schedule",     # Figs 3/4/6 + steady-state throughput
@@ -42,7 +47,12 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
     mods = [args.only] if args.only else MODULES
+    if not args.only and jax.device_count() < 2:
+        mods = [m for m in mods if m != "shard_scaling"]
+        print("# shard_scaling left out: it needs several devices",
+              file=sys.stderr)
     print("name,us_per_call,derived")
 
     rows = []

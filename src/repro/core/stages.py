@@ -15,6 +15,8 @@ Kernel/selection knobs (DESIGN.md §11/§14) — one consolidated pair on
 
 * ``kernels`` — "auto" | "pallas" | "ref": which implementation backs the
   accelerated paths ("auto" resolves to "pallas" on TPU, "ref" elsewhere).
+  The per-level UCT kernel cannot score PUCT, so ``resolved_uct_kernels``
+  reports "ref" there.
   The old boolean ``use_pallas`` is accepted and forwarded under a
   ``DeprecationWarning``.
 * ``vl_mode`` — in-flight decorrelation statistics (DESIGN.md §15):
@@ -131,8 +133,16 @@ class SearchParams:
         return self.kernels
 
     @property
+    def resolved_uct_kernels(self) -> str:
+        """What scores the per-level descents (scan, lockstep, and the
+        single-root strategies): ``resolved_kernels``, except "ref" under
+        PUCT — the ``uct_select`` kernel takes no priors.  The fused
+        megakernel scores PUCT in-kernel either way."""
+        return "ref" if self.puct else self.resolved_kernels
+
+    @property
     def pallas_enabled(self) -> bool:
-        return self.resolved_kernels == "pallas"
+        return self.resolved_uct_kernels == "pallas"
 
     @property
     def resolved_wave_select(self) -> str:
@@ -141,7 +151,7 @@ class SearchParams:
                 f"wave_select must be one of {WAVE_SELECT_MODES}, "
                 f"got {self.wave_select!r}")
         if self.wave_select == "auto":
-            return "mega" if self.pallas_enabled else "scan"
+            return "mega" if self.resolved_kernels == "pallas" else "scan"
         return self.wave_select
 
 

@@ -65,11 +65,20 @@ def uct_scores(child_n, child_w, child_vl, parent_n, cp, *, vl_weight=1.0,
     return jnp.where(n_eff < 0.5, jnp.float32(1e30), scores)
 
 
+def _check_kernel(use_pallas, puct):
+    if use_pallas and puct:
+        raise ValueError("the Pallas UCT kernel takes no priors and cannot "
+                         "score PUCT; pass use_pallas=False (see "
+                         "SearchParams.resolved_uct_kernels)")
+
+
 def uct_argmax(child_n, child_w, child_vl, parent_n, cp, *, vl_weight=1.0,
                prior=None, puct=False, valid=None, use_pallas=False,
                interpret=False, child_o=None, vl_mode="loss"):
-    """Best child index along the last axis. ``valid`` masks illegal slots."""
-    if use_pallas and not puct:
+    """Best child index along the last axis. ``valid`` masks illegal slots.
+    The Pallas kernel takes no priors: ``use_pallas`` with ``puct`` raises."""
+    _check_kernel(use_pallas, puct)
+    if use_pallas:
         from repro.kernels.uct_select import ops as uops
         return uops.uct_argmax(child_n, child_w, child_vl, parent_n,
                                cp=cp, vl_weight=vl_weight,
@@ -107,7 +116,8 @@ def uct_argmax_running(child_n, child_w, child_vl, parent_n, parent_id, cp, *,
     lanes, a = child_n.shape
     if valid is None:
         valid = jnp.ones((lanes, a), bool)
-    if use_pallas and not puct:
+    _check_kernel(use_pallas, puct)
+    if use_pallas:
         from repro.kernels.uct_select import ops as uops
         return uops.uct_argmax_running(
             child_n, child_w, child_vl, parent_n, parent_id,

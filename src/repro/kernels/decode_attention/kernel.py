@@ -92,4 +92,5 @@ def decode_attention_bhd(q, k, v, valid_len, *, scale: float,
         compiler_params=tpu_compiler_params(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        name="decode_attention",
     )(valid_len, q, k, v)

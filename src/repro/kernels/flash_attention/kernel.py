@@ -105,4 +105,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool, scale: float,
         compiler_params=tpu_compiler_params(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

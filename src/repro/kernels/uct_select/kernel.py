@@ -66,6 +66,7 @@ def uct_argmax_tiles(child_n, child_w, child_vl, child_o, parent_n, valid, *,
         compiler_params=tpu_compiler_params(
             dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
+        name="uct_select",
     )(child_n, child_w, child_vl, child_o, parent_n, valid)
 
 
@@ -144,4 +145,5 @@ def uct_argmax_running_call(child_n, child_w, child_vl, child_o, parent_n,
         out_shape=jax.ShapeDtypeStruct((r, 1), jnp.int32),
         compiler_params=tpu_compiler_params(dimension_semantics=()),
         interpret=interpret,
+        name="uct_select_running",
     )(child_n, child_w, child_vl, child_o, parent_n, valid, parent_id)

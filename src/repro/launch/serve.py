@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.base import count_params, get_family
 from repro.serving.engine import EngineConfig, Request, ServingEngine
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--mcts-budget", type=int, default=16)
     ap.add_argument("--mcts-lanes", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family in ("whisper",):

@@ -45,6 +45,12 @@ Params = Any
 Pytree = Any
 
 
+# families whose Pallas kernels (the rwkv6 and zamba2 scans) have no TPU
+# compile guard in tests/test_tpu_compile.py: ``use_pallas=None`` keeps them
+# on their jnp references
+_PALLAS_UNGUARDED = ("rwkv6", "zamba2")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -119,11 +125,24 @@ class ModelConfig:
     remat: bool = True
     use_scan: bool = True
     ce_chunk: int = 512              # chunked cross-entropy block (tokens)
-    use_pallas: bool = False         # kernel path (TPU); False = jnp reference
+    # Pallas kernels on the model's inference path; read through ``pallas``
+    use_pallas: Optional[bool] = None
     max_seq: int = 8192              # rope table default cap (runtime extends)
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    @property
+    def pallas(self) -> bool:
+        """``use_pallas`` resolved, the one rule every family reads: True or
+        False forces a side (e.g. for parity); None follows the platform
+        (Pallas on TPU, the jnp references elsewhere) except for the
+        families whose kernels have no TPU compile guard, which keep their
+        references."""
+        if self.use_pallas is None:
+            return (jax.default_backend() == "tpu"
+                    and self.family not in _PALLAS_UNGUARDED)
+        return self.use_pallas
 
     @property
     def kv_heads(self) -> int:

@@ -297,19 +297,23 @@ def blocked_attention(q, k, v, *, causal: bool, q_offset=0, blk_q=256,
 
 
 def attention(cfg: ModelConfig, q, k, v, *, causal: bool, q_offset=0,
-              kv_valid_len=None, logits_soft_cap: float = 0.0):
-    """Dispatch: Pallas flash kernels on TPU, blocked or materialized jnp
-    reference elsewhere.
+              kv_valid_len=None, logits_soft_cap: float = 0.0,
+              kernels: bool = False):
+    """Dispatch: Pallas flash kernels where ``kernels`` and ``cfg.pallas``
+    allow, blocked or materialized jnp reference otherwise.
 
     ``kv_valid_len`` [B] masks a pre-allocated KV cache beyond the filled
-    prefix (decode path).
+    prefix (decode path).  ``kernels`` marks an inference caller: the
+    kernels have no VJP, so a call that may be differentiated leaves it
+    False.  They implement neither a logit soft cap nor a query offset.
     """
-    if cfg.use_pallas and kv_valid_len is None and q.shape[1] > 1:
-        from repro.kernels.flash_attention import ops as fa
-        return fa.flash_attention(q, k, v, causal=causal, q_offset=q_offset)
-    if cfg.use_pallas and q.shape[1] == 1 and kv_valid_len is not None:
-        from repro.kernels.decode_attention import ops as da
-        return da.decode_attention(q, k, v, kv_valid_len)
+    if kernels and cfg.pallas and not logits_soft_cap:
+        if kv_valid_len is None and q.shape[1] > 1 and q_offset == 0:
+            from repro.kernels.flash_attention import ops as fa
+            return fa.flash_attention(q, k, v, causal=causal)
+        if q.shape[1] == 1 and kv_valid_len is not None:
+            from repro.kernels.decode_attention import ops as da
+            return da.decode_attention(q, k, v, kv_valid_len)
     if cfg.attn_impl == "blocked" and kv_valid_len is None and q.shape[1] > 1:
         return blocked_attention(q, k, v, causal=causal, q_offset=q_offset,
                                  blk_q=cfg.attn_blk_q, blk_k=cfg.attn_blk_k,

@@ -151,7 +151,7 @@ def _block_seq(cfg: ModelConfig, lp, x, state):
     h1 = L.layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
     prev = _shift_seq(h1, state["tm_prev"])
     out, wkv = _time_mix(cfg, lp["time_mix"], h1, prev, state["wkv"],
-                         use_pallas=cfg.use_pallas)
+                         use_pallas=cfg.pallas)
     x = x + out
     h2 = L.layernorm(x, lp["ln2"]["scale"], lp["ln2"]["bias"])
     prev2 = _shift_seq(h2, state["cm_prev"])

@@ -89,7 +89,8 @@ def _block(cfg: ModelConfig, p, x, cos, sin, *, causal=True, q_offset=0,
         ck = ck.at[jnp.arange(b), pos].set(k[:, 0].astype(ck.dtype))
         cv = cv.at[jnp.arange(b), pos].set(v[:, 0].astype(cv.dtype))
         k, v, new_kv = ck, cv, (ck, cv)
-        attn_out = L.attention(cfg, q, k, v, causal=False, kv_valid_len=kv_valid_len)
+        attn_out = L.attention(cfg, q, k, v, causal=False,
+                               kv_valid_len=kv_valid_len, kernels=True)
     else:
         attn_out = L.attention(cfg, q, k, v, causal=causal, q_offset=q_offset)
     b, s = x.shape[:2]
@@ -175,7 +176,7 @@ def _prefill_stack(cfg: ModelConfig, params, tokens):
         q, k, v = L.gqa_project_qkv(cfg, lp["attn"], h)
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
-        attn_out = L.attention(cfg, q, k, v, causal=True)
+        attn_out = L.attention(cfg, q, k, v, causal=True, kernels=True)
         y = y + (attn_out.reshape(b, s, -1) @ lp["attn"]["wo"]) * cfg.residual_scale
         h = L.apply_norm(cfg, lp["ln2"], y)
         y = y + L.apply_mlp(cfg, lp["mlp"], h) * cfg.residual_scale
@@ -244,8 +245,8 @@ def prefill_fn(cfg: ModelConfig, params, toks, plen):
 def step_fn(cfg: ModelConfig, params, cache, tok, pos):
     """One incremental token: cache {k, v: [L, S, Hkv, D]}, tok/pos scalars
     -> (logits [V] f32 for position pos+1, cache).  Attention reads the
-    per-layer cache row through ``kernels/decode_attention`` (Pallas on TPU
-    when ``cfg.use_pallas``, the jnp flash-decode oracle elsewhere).
+    per-layer cache row through ``kernels/decode_attention`` (Pallas when
+    ``cfg.pallas``, i.e. on TPU by default; the jnp oracle elsewhere).
 
     Kept as its own scan body rather than reusing ``_block``: the cache here
     is unbatched (rows are vmapped by the search strategies), and the
@@ -270,7 +271,7 @@ def step_fn(cfg: ModelConfig, params, cache, tok, pos):
         ck = jax.lax.dynamic_update_slice(ck, k[0].astype(ck.dtype), (pos, 0, 0))
         cv = jax.lax.dynamic_update_slice(cv, v[0].astype(cv.dtype), (pos, 0, 0))
         attn_out = da.decode_attention(q, ck[None], cv[None], valid,
-                                       use_ref=not cfg.use_pallas)
+                                       use_ref=not cfg.pallas)
         y = y + (attn_out.reshape(1, 1, -1) @ lp["attn"]["wo"]) * cfg.residual_scale
         h = L.apply_norm(cfg, lp["ln2"], y)
         y = y + L.apply_mlp(cfg, lp["mlp"], h) * cfg.residual_scale

@@ -147,7 +147,7 @@ def _mamba_block(cfg: ModelConfig, p, x, state):
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"])
     y, new_ssd = ssd_ops.ssd(xs, dt, A, Bm, Cm, p["D"], state["ssd"],
-                             use_pallas=cfg.use_pallas)
+                             use_pallas=cfg.pallas)
     y = y.reshape(b, s, d_inner)
     y = L.rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                   p["gate_norm"]["scale"])

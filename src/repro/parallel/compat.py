@@ -1,50 +1,34 @@
-"""jax version compatibility for the distributed + kernel layers.
+"""The distributed + kernel layers' one seam onto the jax API.
 
-The repo targets current jax (`jax.shard_map`, `check_vma`, mesh
-``axis_types``, ``pltpu.CompilerParams``); older releases (e.g. 0.4.x,
-where shard_map lives under ``jax.experimental.shard_map`` with
-``check_rep``, ``make_mesh`` has no ``axis_types``, and the compiler params
-dataclass is ``TPUCompilerParams``) are supported through these wrappers.
-All repo code and tests go through them instead of calling jax directly —
-CI validates both branches via its jax version matrix.
+The repo targets the installed jax (0.9: ``jax.shard_map`` with
+``check_vma``, mesh ``axis_types``, ``pltpu.CompilerParams``).  Repo code
+and tests go through these wrappers instead of calling jax directly, so an
+API move touches this module only.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:                      # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name):
-    """jax.lax.axis_size fallback: psum of 1 over the axis, which resolves
-    to a static int inside shard_map on old jax too."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a mesh axis inside ``shard_map``."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the arg exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_num_devices(mesh) -> int:
-    """Total device count of a mesh (``mesh.size`` on every supported jax;
-    kept here so sharding callers have a single seam if the Mesh API drifts)."""
+    """Total device count of a mesh (``mesh.size``; kept here so sharding
+    callers have a single seam if the Mesh API drifts)."""
     return int(mesh.size)
 
 
@@ -55,13 +39,8 @@ def mesh_from_devices(devices, axis: str = "batch"):
     new world is an arbitrary subset, so the Mesh is built directly."""
     import numpy as np
     devs = np.asarray(list(devices), dtype=object)
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.sharding.Mesh(devs, (axis,),
-                                     axis_types=(jax.sharding.AxisType.Auto,))
-        except TypeError:                # older signature without axis_types
-            pass
-    return jax.sharding.Mesh(devs, (axis,))
+    return jax.sharding.Mesh(devs, (axis,),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def process_count() -> int:
@@ -103,10 +82,7 @@ def init_distributed_cpu(coordinator: str, num_processes: int,
     XLA:CPU only executes multi-process programs with the gloo collectives
     backend; the config flag must be set before the backend initializes, so
     this must be the first jax call of the process."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — flag absent: backend defaults suffice
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -127,10 +103,6 @@ def replicated_sharding(mesh):
 
 
 def tpu_compiler_params(*, dimension_semantics):
-    """Pallas TPU compiler params across the rename: current jax exposes
-    ``pltpu.CompilerParams``, 0.4.x the same dataclass as
-    ``pltpu.TPUCompilerParams``."""
+    """Pallas TPU compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

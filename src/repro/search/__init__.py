@@ -9,6 +9,8 @@ Entry points
     search(domain, cfg, rng)            one search, jit/vmap-compatible
     search_batch(domains, cfg, rng)     B searches in ONE device program
                                         (auto-shards over a device mesh)
+    search_keys(domains, cfg, keys)     the same on one device with the
+                                        per-root keys given
     shard_search_batch(...)             the explicit mesh-sharded form
                                         (single- or multi-host meshes)
     ft_search_batch(...)                the elastic fault-tolerant driver
@@ -35,7 +37,8 @@ See README.md (quickstart), DESIGN.md §3–§5 (API design), §9 (sharding),
 from repro.core.stages import SearchParams  # noqa: F401  (re-export)
 from repro.search.api import (STATS_KEYS, SearchConfig,  # noqa: F401
                               SearchResult, get_strategy, list_strategies,
-                              register_strategy, search, search_batch)
+                              register_strategy, search, search_batch,
+                              search_keys)
 from repro.search.domain import (Domain, SupportsPriors,  # noqa: F401
                                  check_domain)
 from repro.search.sharding import (shard_search_batch,  # noqa: F401
@@ -47,7 +50,8 @@ from repro.search import strategies  # noqa: F401  (registers the built-ins)
 __all__ = [
     "STATS_KEYS", "SearchConfig", "SearchParams", "SearchResult",
     "Domain", "SupportsPriors", "check_domain",
-    "search", "search_batch", "shard_search_batch", "shard_search_keys",
+    "search", "search_batch", "search_keys", "shard_search_batch",
+    "shard_search_keys",
     "ElasticSearchDriver", "FTReport", "FTSearchConfig", "ft_search_batch",
     "get_strategy", "list_strategies", "register_strategy",
     "strategies",

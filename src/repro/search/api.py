@@ -239,11 +239,20 @@ def search_batch(domains: Sequence[Any], cfg: SearchConfig, rng,
     if mesh is not None and mesh is not False:
         from repro.search.sharding import shard_search_batch
         return shard_search_batch(domains, cfg, rng, mesh=mesh)
-    rngs = jax.random.split(rng, len(domains))
+    return search_keys(domains, cfg, jax.random.split(rng, len(domains)))
+
+
+def search_keys(domains: Sequence[Any], cfg: SearchConfig, keys
+                ) -> SearchResult:
+    """``search_batch`` on one device with the per-root keys already split:
+    row i is ``search(domains[i], cfg, keys[i])``.  One vmap and no device
+    placement, so it also serves as the per-device body of a ``shard_map``
+    (the serving searchers' mesh path)."""
+    domains = list(domains)
     make, batched = _batch_domains(domains)
     if batched is None:
-        return jax.vmap(lambda r: search(domains[0], cfg, r))(rngs)
-    return jax.vmap(lambda bat, r: search(make(bat), cfg, r))(batched, rngs)
+        return jax.vmap(lambda r: search(domains[0], cfg, r))(keys)
+    return jax.vmap(lambda bat, r: search(make(bat), cfg, r))(batched, keys)
 
 
 def _contains_tracer(*objs) -> bool:

@@ -28,17 +28,18 @@ Contracts (tested in tests/test_sharding.py and tests/test_multihost.py):
 * **Padding**: B is padded up to a multiple of the mesh's device count by
   repeating row 0 (a valid domain + key); padded rows run a real search
   whose outputs are sliced off before returning.
-* **Version compat**: meshes and shardings are built through
-  ``repro.parallel.compat`` (jax 0.4.37 and current jax).
+* **API seam**: meshes and shardings are built through
+  ``repro.parallel.compat``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.parallel.compat import (batch_sharding, global_batch_put,
                                    mesh_is_multihost, mesh_num_devices,
-                                   replicate_to_hosts)
+                                   replicate_to_hosts, shard_map)
 
 __all__ = ["shard_search_batch"]
 
@@ -55,6 +56,18 @@ def _pad_rows(x, pad: int):
         return x
     fill = jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])
     return jnp.concatenate([x, fill], axis=0)
+
+
+def per_device(f, mesh, n_args: int, replicated=()):
+    """``f`` run by every device of ``mesh`` on its own rows: its
+    ``n_args`` arguments (but the ``replicated`` positions) and every
+    output are split along their leading axis over the mesh's first axis.
+    A ``shard_map``, not compiler partitioning: a Mosaic (Pallas TPU)
+    kernel inside ``f`` cannot be partitioned automatically."""
+    spec = P(mesh.axis_names[0])
+    in_specs = tuple(P() if i in replicated else spec for i in range(n_args))
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                     check_vma=False)
 
 
 def shard_search_batch(domains, cfg, rng, *, mesh=None):
@@ -96,16 +109,17 @@ def shard_search_keys(domains, cfg, keys, *, mesh=None):
     sharded = batch_sharding(mesh)
     multihost = mesh_is_multihost(mesh)
     rngs = global_batch_put(_pad_rows(keys, pad), sharded)
+    # each device vmaps over its own rows (per_device)
     if batched is None:
         d0 = domains[0]
-        fn = jax.jit(jax.vmap(lambda r: search(d0, cfg, r)),
-                     out_shardings=sharded)
+        fn = jax.jit(per_device(jax.vmap(lambda r: search(d0, cfg, r)),
+                                mesh, 1))
         res = fn(rngs)
     else:
         batched = jax.tree_util.tree_map(
             lambda x: global_batch_put(_pad_rows(x, pad), sharded), batched)
-        fn = jax.jit(jax.vmap(lambda bat, r: search(make(bat), cfg, r)),
-                     out_shardings=sharded)
+        fn = jax.jit(per_device(
+            jax.vmap(lambda bat, r: search(make(bat), cfg, r)), mesh, 2))
         res = fn(batched, rngs)
     if multihost:
         res = replicate_to_hosts(res, mesh)

@@ -57,9 +57,10 @@ import numpy as np
 from repro.core.domains.lm_decode import CachedLMDecodeDomain, LMDecodeDomain
 from repro.core.tree import init_tree, reroot, reroot_ok
 from repro.models.base import ModelConfig, seq_prefill, seq_step
-from repro.parallel.compat import (batch_sharding, mesh_num_devices,
-                                   replicated_sharding)
-from repro.search import SearchConfig, SearchParams, search_batch
+from repro.parallel.compat import (batch_sharding, global_batch_put,
+                                   mesh_num_devices, replicated_sharding)
+from repro.search import SearchConfig, SearchParams, search_keys
+from repro.search.sharding import per_device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -213,19 +214,27 @@ class ReusableSearcher:
 
     def __init__(self, cfg: ModelConfig, params, dcfg: MCTSDecodeConfig,
                  batch: int, mesh=None):
-        self.cfg, self.params, self.dcfg, self.batch = cfg, params, dcfg, batch
+        self.cfg, self.dcfg, self.batch = cfg, dcfg, batch
         self.mesh = mesh
         ndev = mesh_num_devices(mesh) if mesh is not None else 1
         self.padded = batch + ((-batch) % ndev)
         self.scfg = dcfg.search_config()
+        # the weights are an argument of every program, never a constant
+        # baked into it (_place_weights)
+        self.params = _place_weights(params, mesh)
         if mesh is None:
             self._jstep = jax.jit(self._step_impl)
+            self._jadmit = jax.jit(self._admit_impl)
         else:
             shard, repl = batch_sharding(mesh), replicated_sharding(mesh)
-            self._jstep = jax.jit(self._step_impl,
-                                  in_shardings=(shard, shard, repl, shard),
-                                  out_shardings=(shard, shard))
-        self._jadmit = jax.jit(self._admit_impl)
+            self._jstep = jax.jit(
+                per_device(self._step_impl, mesh, 5, replicated=(0,)),
+                in_shardings=(repl, shard, shard, shard, shard),
+                out_shardings=(shard, shard))
+            self._jadmit = jax.jit(
+                per_device(self._admit_local, mesh, 5,
+                           replicated=(0, 2, 3, 4)),
+                out_shardings=shard)
 
     # -- carry lifecycle ----------------------------------------------------
     def init_carry(self, buf_len: int):
@@ -261,44 +270,59 @@ class ReusableSearcher:
         """Reset slot ``slot`` for a fresh request whose padded prefix is
         ``buf_row`` with true length ``plen``: warm stats back to identity,
         KV row prefilled ONCE (the request's only prefill)."""
-        return self._jadmit(carry, jnp.int32(slot),
+        return self._jadmit(self.params, carry, jnp.int32(slot),
                             jnp.asarray(buf_row, jnp.int32),
                             jnp.int32(plen))
 
-    def _admit_impl(self, carry, slot, buf_row, plen):
+    def _admit_local(self, params, carry, slot, buf_row, plen):
+        """``_admit_impl`` on one device's rows of a mesh-sharded carry:
+        every device prefills (in parallel), the one holding ``slot``
+        keeps the row."""
+        rows = jax.tree_util.tree_leaves(carry)[0].shape[0]
+        local = slot - jax.lax.axis_index(self.mesh.axis_names[0]) * rows
+        local = jnp.where((local >= 0) & (local < rows), local, rows)
+        return self._admit_impl(params, carry, local, buf_row, plen)
+
+    def _admit_impl(self, params, carry, slot, buf_row, plen):
+        """Out-of-range ``slot`` rows are dropped (``_admit_local``)."""
         d = self.dcfg
         new = dict(carry)
         if d.tree_reuse:
             # killing the liveness flag IS the reset: a dead slot's next
             # search starts cold and overwrites the stale arena wholesale
-            new["alive"] = carry["alive"].at[slot].set(False)
+            new["alive"] = carry["alive"].at[slot].set(False, mode="drop")
         if d.kv_splice:
             max_len = buf_row.shape[0] + d.search_depth + d.rollout_len
             toks = jnp.zeros((max_len,), jnp.int32)
             toks = jax.lax.dynamic_update_slice(toks, buf_row, (0,))
-            logits, cache = seq_prefill(self.cfg, self.params, toks, plen)
+            logits, cache = seq_prefill(self.cfg, params, toks, plen)
             new["cache"] = jax.tree_util.tree_map(
-                lambda full, one: full.at[slot].set(one),
+                lambda full, one: full.at[slot].set(one, mode="drop"),
                 carry["cache"], cache)
-            new["logits"] = carry["logits"].at[slot].set(logits)
+            new["logits"] = carry["logits"].at[slot].set(logits, mode="drop")
         return new
 
     # -- per-token step -----------------------------------------------------
+    def _args(self, buf, lens, rng, carry):
+        buf, lens = _pad_slots(buf, lens, self.padded - self.batch)
+        return (self.params, buf, lens, jax.random.split(rng, self.padded),
+                carry)
+
     def step(self, buf, lens, rng, carry):
         """One batched multi-root search over all slots -> each slot's
         chosen token, plus the carry advanced by the committed tokens."""
-        buf = jnp.asarray(buf, jnp.int32)
-        lens = jnp.asarray(lens, jnp.int32)
-        extra = self.padded - self.batch
-        if extra:
-            buf = jnp.concatenate(
-                [buf, jnp.zeros((extra, buf.shape[1]), buf.dtype)])
-            lens = jnp.concatenate([lens, jnp.zeros((extra,), lens.dtype)])
-        toks, carry = self._jstep(buf, lens, rng, carry)
+        toks, carry = self._jstep(*self._args(buf, lens, rng, carry))
         return toks[:self.batch], carry
 
-    def _step_impl(self, buf, lens, rng, carry):
-        cfg, params, d = self.cfg, self.params, self.dcfg
+    def lower(self, buf, lens, rng, carry):
+        """The per-token program ``step`` runs, lowered."""
+        return self._jstep.lower(*self._args(buf, lens, rng, carry))
+
+    def _step_impl(self, params, buf, lens, keys, carry):
+        """Per-slot keys; under a mesh this runs on each device's own slots
+        (so every batch size here is ``buf.shape[0]``)."""
+        cfg, d = self.cfg, self.dcfg
+        rows = buf.shape[0]
         if d.tree_reuse:
             # reroot every slot's arena on its committed action (recycling
             # the abandoned rows); a slot is reusable only if it is alive
@@ -307,7 +331,7 @@ class ReusableSearcher:
                 carry["arena"], carry["action"])
             ar = jax.vmap(reroot)(carry["arena"], carry["action"])
         domains = []
-        for i in range(self.padded):
+        for i in range(rows):
             kw = {}
             if d.kv_splice:
                 kw["root_cache"] = jax.tree_util.tree_map(
@@ -324,7 +348,7 @@ class ReusableSearcher:
                 dom = dataclasses.replace(
                     dom, root_arena=ar_i, root_arena_alive=use[i])
             domains.append(dom)
-        res = search_batch(domains, self.scfg, rng)
+        res = search_keys(domains, self.scfg, keys)
         if d.kv_splice:
             # the carried logits ARE the root's next-token distribution
             tops = jax.vmap(
@@ -336,14 +360,14 @@ class ReusableSearcher:
                 _, top = dom._topk(dom.root_state())
                 return top
             tops = jax.vmap(root_topk)(buf, lens)
-        toks = tops[jnp.arange(self.padded), res.best_action].astype(jnp.int32)
+        toks = tops[jnp.arange(rows), res.best_action].astype(jnp.int32)
         new = dict(carry)
         if d.tree_reuse:
             # the searched arenas + committed actions ARE the carry; the
             # reroot happens lazily at the START of the next step
             new["arena"] = res.tree
             new["action"] = res.best_action.astype(jnp.int32)
-            new["alive"] = jnp.ones((self.padded,), bool)
+            new["alive"] = jnp.ones((rows,), bool)
         if d.kv_splice:
             # advance each root row by the committed token (ONE step, vs a
             # whole-prefix prefill on the cold path)
@@ -354,14 +378,59 @@ class ReusableSearcher:
         return toks, new
 
 
+def _place_weights(params, mesh):
+    """Weights ride into the per-token programs as an argument — closed
+    over, jit would bake them into every executable as constants (a second
+    copy on the device and a far longer compile).  Under a mesh they are
+    replicated onto every device once, here."""
+    if mesh is None:
+        return params
+    repl = replicated_sharding(mesh)
+    return jax.tree_util.tree_map(lambda x: global_batch_put(x, repl),
+                                  params)
+
+
+def _pad_slots(buf, lens, extra: int):
+    """Pad dead rows (len 0 == empty slot: searched, output ignored) up to
+    the padded batch a mesh needs."""
+    buf = jnp.asarray(buf, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    if extra:
+        buf = jnp.concatenate(
+            [buf, jnp.zeros((extra, buf.shape[1]), buf.dtype)])
+        lens = jnp.concatenate([lens, jnp.zeros((extra,), lens.dtype)])
+    return buf, lens
+
+
+class BatchedSearcher:
+    """The stateless per-token searcher: ``(token_buf [B, buf_len] i32,
+    lens [B] i32, rng) -> [B] i32``, one jitted device program that takes
+    the weights as an argument.  ``lower`` exposes that program."""
+
+    def __init__(self, jstep, params, batch: int, padded: int):
+        self._jstep, self.params = jstep, params
+        self.batch, self.padded = batch, padded
+
+    def _args(self, buf, lens, rng):
+        buf, lens = _pad_slots(buf, lens, self.padded - self.batch)
+        return self.params, buf, lens, jax.random.split(rng, self.padded)
+
+    def __call__(self, buf, lens, rng):
+        return self._jstep(*self._args(buf, lens, rng))[:self.batch]
+
+    def lower(self, buf, lens, rng):
+        return self._jstep.lower(*self._args(buf, lens, rng))
+
+
 def make_batched_searcher(cfg: ModelConfig, params, dcfg: MCTSDecodeConfig,
                           batch: int, mesh=None):
     """Factory for the per-token batched searcher.
 
-    Stateless (default): returns ``(token_buf [B, buf_len] i32, lens [B]
-    i32, rng) -> [B] i32`` — one jitted device program that searches all B
-    prefixes cold and returns each slot's chosen next token.  Shapes are
-    static, so one compilation serves every decode step.
+    Stateless (default): returns a ``BatchedSearcher``, ``(token_buf
+    [B, buf_len] i32, lens [B] i32, rng) -> [B] i32`` — one jitted device
+    program that searches all B prefixes cold and returns each slot's
+    chosen next token.  Shapes are static, so one compilation serves every
+    decode step.
 
     Stateful (``dcfg.kv_splice`` or ``dcfg.tree_reuse``): returns a
     ``ReusableSearcher`` whose ``step`` additionally threads the per-slot
@@ -383,38 +452,32 @@ def make_batched_searcher(cfg: ModelConfig, params, dcfg: MCTSDecodeConfig,
     ndev = mesh_num_devices(mesh) if mesh is not None else 1
     padded = batch + ((-batch) % ndev)
 
-    def root_topk(buf_row, len_row):
-        d = _domain(cfg, params, buf_row, dcfg, prompt_len=len_row)
-        _, top = d._topk(d.root_state())
-        return top
+    def step(params, buf, lens, keys):
+        """Per-slot keys; under a mesh each device runs its own slots."""
+        def root_topk(buf_row, len_row):
+            d = _domain(cfg, params, buf_row, dcfg, prompt_len=len_row)
+            _, top = d._topk(d.root_state())
+            return top
 
-    def step(buf, lens, rng):
+        rows = buf.shape[0]
         domains = [_domain(cfg, params, buf[i], dcfg, prompt_len=lens[i])
-                   for i in range(padded)]
-        res = search_batch(domains, scfg, rng)
-        tops = jax.vmap(root_topk)(buf, lens)            # [padded, A], one pass
-        return tops[jnp.arange(padded), res.best_action].astype(jnp.int32)
+                   for i in range(rows)]
+        res = search_keys(domains, scfg, keys)
+        tops = jax.vmap(root_topk)(buf, lens)            # [rows, A], one pass
+        return tops[jnp.arange(rows), res.best_action].astype(jnp.int32)
 
     if mesh is None:
-        return jax.jit(step)
-
-    # the batch axis of buf/lens (and of every intermediate, via sharding
-    # propagation) is split over the mesh; the scalar rng key is replicated
-    shard = batch_sharding(mesh)
-    repl = replicated_sharding(mesh)
-    jstep = jax.jit(step, in_shardings=(shard, shard, repl),
-                    out_shardings=shard)
-
-    def sharded_step(buf, lens, rng):
-        extra = padded - batch
-        if extra:
-            # pad dead rows (len 0 == empty slot: searched, output ignored)
-            buf = jnp.concatenate(
-                [buf, jnp.zeros((extra, buf.shape[1]), buf.dtype)])
-            lens = jnp.concatenate([lens, jnp.zeros((extra,), lens.dtype)])
-        return jstep(buf, lens, rng)[:batch]
-
-    return sharded_step
+        jstep = jax.jit(step)
+    else:
+        # buf/lens/keys split along the slot axis, one shard per device;
+        # the weights are replicated
+        shard = batch_sharding(mesh)
+        repl = replicated_sharding(mesh)
+        jstep = jax.jit(per_device(step, mesh, 4, replicated=(0,)),
+                        in_shardings=(repl, shard, shard, shard),
+                        out_shardings=shard)
+    return BatchedSearcher(jstep, _place_weights(params, mesh), batch,
+                           padded)
 
 
 def _pad_prompts(prompts, n_tokens: int):

@@ -55,8 +55,7 @@ def test_decode_attention(b, sk, h, hkv, d):
 # CachedLMDecodeDomain, DESIGN.md §10) actually issues: non-power-of-two
 # cache rows sized prompt+depth+rollout, with per-row valid lengths
 # ``pos + 1`` anywhere from a 1-token prefix up to the full row.  The kernel
-# builds its compiler params through ``compat.tpu_compiler_params``, so these
-# cases pass on jax 0.4.37 and latest alike.
+# builds its compiler params through ``compat.tpu_compiler_params``.
 @pytest.mark.parametrize("b,sk,h,hkv,d", [
     (4, 28, 4, 2, 8),       # test-size row: plen 16 + depth 8 + rollout 4
     (2, 44, 4, 2, 16),      # bench smoke row: plen 32 + depth 8 + rollout 4

@@ -66,6 +66,21 @@ def test_wave_select_resolution():
         _ = SearchParams(kernels="nope").resolved_kernels
 
 
+def test_puct_reports_ref_uct_kernels():
+    """The per-level UCT kernel takes no priors: under PUCT the reported
+    resolution says "ref" while the megakernel stays Pallas, and asking the
+    kernel for PUCT directly raises instead of degrading unseen."""
+    sp = SearchParams(kernels="pallas", puct=True)
+    assert sp.resolved_kernels == "pallas"
+    assert sp.resolved_wave_select == "mega"
+    assert sp.resolved_uct_kernels == "ref" and not sp.pallas_enabled
+    assert SearchParams(kernels="pallas").resolved_uct_kernels == "pallas"
+    ones = jnp.ones((2, 4))
+    with pytest.raises(ValueError, match="PUCT"):
+        uct.uct_argmax(ones, ones, ones, jnp.ones((2,)), 1.0, prior=ones,
+                       puct=True, use_pallas=True)
+
+
 # ---------------------------------------------------------------------------
 # exact parity at lanes=1 (and scan reproduces the default path bit-for-bit)
 # ---------------------------------------------------------------------------
