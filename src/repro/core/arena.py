@@ -57,6 +57,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 UNEXPANDED = -1
 ROOT = 0
 
@@ -117,9 +119,11 @@ def init_arena(root_state, num_actions: int, max_nodes: int,
                root_terminal=False) -> TreeArena:
     """Fresh arena: root at row 0, every other row unallocated."""
     a = num_actions
-    state = jax.tree_util.tree_map(
-        lambda x: jnp.zeros((max_nodes,) + jnp.shape(x), jnp.asarray(x).dtype)
-        .at[ROOT].set(x), root_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        state = jax.tree_util.tree_map(
+            lambda x: jnp.zeros((max_nodes,) + jnp.shape(x),
+                                jnp.asarray(x).dtype).at[ROOT].set(x),
+            root_state)
     return TreeArena(
         visits=jnp.zeros((max_nodes,), jnp.int32),
         value=jnp.zeros((max_nodes,), jnp.float32),
@@ -249,7 +253,8 @@ def compact(arena: TreeArena, keep, new_root=ROOT) -> TreeArena:
     pr = gather(arena.parent, UNEXPANDED)
     pr = jnp.where(pr >= 0, remap[jnp.maximum(pr, 0)], UNEXPANDED)
     pr = pr.at[ROOT].set(UNEXPANDED)
-    state = jax.tree_util.tree_map(lambda p: gather(p, 0), arena.state)
+    with jax.named_scope(scopes.NODE_STATE):
+        state = jax.tree_util.tree_map(lambda p: gather(p, 0), arena.state)
     return arena.replace(
         visits=gather(arena.visits, 0),
         value=gather(arena.value, 0.0),

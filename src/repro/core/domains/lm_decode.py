@@ -30,6 +30,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.models.base import ModelConfig, get_family, seq_prefill, seq_step
 
 
@@ -72,6 +73,7 @@ class LMDecodeDomain:
             return jnp.int32(self.prompt.shape[0])
         return jnp.asarray(self.prompt_len, jnp.int32)
 
+    @jax.named_scope(scopes.ROOT)
     def root_state(self):
         toks = jnp.zeros((self.max_len,), jnp.int32)
         toks = jax.lax.dynamic_update_slice(toks, self.prompt.astype(jnp.int32), (0,))
@@ -84,7 +86,8 @@ class LMDecodeDomain:
 
     def _topk(self, state):
         logits = self._last_logits(state["toks"], state["len"])
-        return jax.lax.top_k(logits, self.num_actions)
+        with jax.named_scope(scopes.TOPK):
+            return jax.lax.top_k(logits, self.num_actions)
 
     # -- domain API ----------------------------------------------------------
     def step(self, state, action):
@@ -147,6 +150,7 @@ class CachedLMDecodeDomain(LMDecodeDomain):
     root_logits: Any = None           # next-token logits paired with
                                       # root_cache
 
+    @jax.named_scope(scopes.ROOT)
     def root_state(self):
         if self.root_cache is not None:
             return {"len": self._plen(), "cache": self.root_cache,
@@ -160,6 +164,7 @@ class CachedLMDecodeDomain(LMDecodeDomain):
     def _state_logits(self, state):
         return state["logits"].astype(jnp.float32) / self.temperature
 
+    @jax.named_scope(scopes.TOPK)
     def _topk(self, state):
         return jax.lax.top_k(self._state_logits(state), self.num_actions)
 

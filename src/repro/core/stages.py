@@ -54,7 +54,7 @@ from typing import Any, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import uct
+from repro.core import scopes, uct
 from repro.core.arena import alloc as arena_alloc
 from repro.core.tree import ROOT, UNEXPANDED, Tree, get_state, max_nodes
 
@@ -155,6 +155,7 @@ class SearchParams:
         return self.wave_select
 
 
+@jax.named_scope(scopes.TREE)
 def empty_selection(sp: SearchParams, lanes: int):
     return {
         "path": jnp.full((lanes, sp.path_len), UNEXPANDED, jnp.int32),
@@ -167,6 +168,7 @@ def empty_selection(sp: SearchParams, lanes: int):
     }
 
 
+@jax.named_scope(scopes.NODE_STATE)
 def empty_expansion(sp: SearchParams, lanes: int, domain):
     state = jax.tree_util.tree_map(
         lambda x: jnp.zeros((lanes,) + jnp.shape(x), jnp.asarray(x).dtype),
@@ -180,6 +182,7 @@ def empty_expansion(sp: SearchParams, lanes: int, domain):
     }
 
 
+@jax.named_scope(scopes.TREE)
 def empty_playout(sp: SearchParams, lanes: int, num_actions: int):
     return {
         "path": jnp.full((lanes, sp.path_len), UNEXPANDED, jnp.int32),
@@ -205,6 +208,7 @@ def with_infl(tree: Tree, sp: SearchParams, plane) -> Tree:
 # ---------------------------------------------------------------------------
 # SELECT — UCT descent with in-flight decorrelation (serial stage)
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.TREE)
 def select_one(tree: Tree, sp: SearchParams, valid):
     """Descend from the root; returns (tree+in-flight, trajectory dict)."""
     def cond(c):
@@ -240,6 +244,7 @@ def select_one(tree: Tree, sp: SearchParams, valid):
     return tree, sel
 
 
+@jax.named_scope(scopes.TREE)
 def select_wave_scan(tree: Tree, sp: SearchParams, lanes: int, valid):
     """Lane-major Select: lane i+1 sees lane i's virtual loss (paper Fig. 5:
     one serial Select stage feeding multiple playout stages)."""
@@ -262,6 +267,7 @@ def select_wave_scan(tree: Tree, sp: SearchParams, lanes: int, valid):
     return tree, sels
 
 
+@jax.named_scope(scopes.TREE)
 def select_wave_fused(tree: Tree, sp: SearchParams, lanes: int, valid):
     """Depth-major lockstep Select (DESIGN.md §11): every loop iteration is
     one tree level, scoring all active lanes' children with a single batched
@@ -363,6 +369,7 @@ def select_wave(tree: Tree, sp: SearchParams, lanes: int, valid):
 # ---------------------------------------------------------------------------
 # EXPAND — allocate one child per trajectory (serial stage)
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.TREE)
 def expand_one(tree: Tree, domain, sp: SearchParams, sel):
     leaf, depth, valid = sel["leaf"], sel["depth"], sel["valid"]
     row = tree.children[leaf]
@@ -370,14 +377,17 @@ def expand_one(tree: Tree, domain, sp: SearchParams, sel):
     can_try = valid & has_slot & ~tree.terminal[leaf]
     tree, new, can = arena_alloc(tree, can_try)
     a = jnp.argmax(row == UNEXPANDED).astype(jnp.int32)
-    parent_state = get_state(tree, leaf)
-    child_state = domain.step(parent_state, a)
-    term = domain.is_terminal(child_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        parent_state = get_state(tree, leaf)
+    with jax.named_scope(scopes.EXPAND):
+        child_state = domain.step(parent_state, a)
+        term = domain.is_terminal(child_state)
 
     nmax = max_nodes(tree)
-    state = jax.tree_util.tree_map(
-        lambda buf, s: buf.at[new].set(s, mode="drop"),
-        tree.state, child_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        state = jax.tree_util.tree_map(
+            lambda buf, s: buf.at[new].set(s, mode="drop"),
+            tree.state, child_state)
     infl_upd = {("unobs" if sp.wu else "vloss"):
                 infl_plane(tree, sp).at[new].add(1, mode="drop")}
     tree = tree.replace(
@@ -390,15 +400,17 @@ def expand_one(tree: Tree, domain, sp: SearchParams, sel):
 
     node = jnp.where(can, new, leaf)
     path = sel["path"].at[depth + 1].set(jnp.where(can, new, UNEXPANDED))
-    state = jax.tree_util.tree_map(
-        lambda s_par, s_ch: jnp.where(
-            jnp.reshape(can, (1,) * jnp.ndim(s_ch)), s_ch, s_par)
-        if jnp.ndim(s_ch) else jnp.where(can, s_ch, s_par),
-        parent_state, child_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        state = jax.tree_util.tree_map(
+            lambda s_par, s_ch: jnp.where(
+                jnp.reshape(can, (1,) * jnp.ndim(s_ch)), s_ch, s_par)
+            if jnp.ndim(s_ch) else jnp.where(can, s_ch, s_par),
+            parent_state, child_state)
     return tree, {"path": path, "node": node, "is_new": can, "state": state,
                   "valid": valid}
 
 
+@jax.named_scope(scopes.TREE)
 def expand_wave(tree: Tree, domain, sp: SearchParams, sels):
     def body(tr, sel):
         tr, exp = expand_one(tr, domain, sp, sel)
@@ -411,6 +423,7 @@ def expand_wave(tree: Tree, domain, sp: SearchParams, sels):
 # ---------------------------------------------------------------------------
 # PLAYOUT — parallel stage (vmap over lanes; paper Fig. 5 replicated stage)
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.PLAYOUT)
 def playout_wave(domain, sp: SearchParams, exp, rng):
     lanes = exp["node"].shape[0]
     rngs = jax.random.split(rng, lanes)
@@ -428,6 +441,7 @@ def playout_wave(domain, sp: SearchParams, exp, rng):
 # ---------------------------------------------------------------------------
 # BACKUP — scatter-add along paths (commutative => order-independent)
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.TREE)
 def backup_wave(tree: Tree, po, sp: Optional[SearchParams] = None):
     """Scatter-add N/W along paths and drain the mode's in-flight plane.
     ``sp=None`` keeps the historical signature and means "loss" mode."""

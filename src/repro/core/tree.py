@@ -20,6 +20,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.arena import (ROOT, UNEXPANDED, TreeArena, init_arena,
                               live_mask)
 from repro.core.arena import reroot as _arena_reroot
@@ -28,6 +29,7 @@ from repro.core.arena import reroot_ok  # noqa: F401  (re-export)
 Tree = TreeArena
 
 
+@jax.named_scope(scopes.TREE)
 def init_tree(domain, max_nodes: int) -> Tree:
     """Build the search tree for ``domain``.
 
@@ -51,9 +53,11 @@ def init_tree(domain, max_nodes: int) -> Tree:
     if carried is not None:
         alive = getattr(domain, "root_arena_alive", None)
         alive = jnp.asarray(True if alive is None else alive, bool)
-        tree = jax.tree_util.tree_map(
-            lambda c, f: jnp.where(
-                jnp.reshape(alive, (1,) * jnp.ndim(f)), c, f), carried, tree)
+        with jax.named_scope(scopes.NODE_STATE):
+            tree = jax.tree_util.tree_map(
+                lambda c, f: jnp.where(
+                    jnp.reshape(alive, (1,) * jnp.ndim(f)), c, f),
+                carried, tree)
     return tree
 
 
@@ -96,6 +100,7 @@ def root_carry(tree: Tree, action) -> Dict[str, Any]:
     }
 
 
+@jax.named_scope(scopes.TREE)
 def reroot(tree: Tree, action) -> Tree:
     """Promote root child ``action`` to the root and recycle the abandoned
     rows (``core.arena.reroot``).  Returns the rerooted arena — the next
@@ -105,6 +110,7 @@ def reroot(tree: Tree, action) -> Tree:
     return _arena_reroot(tree, action)
 
 
+@jax.named_scope(scopes.TREE)
 def warm_start_root(tree: Tree, carry: Dict[str, Any]) -> Tree:
     """Seed a fresh tree's root from a ``RootCarry`` (cross-token subtree
     reuse, DESIGN.md §12): root N/W start at the carried child's counts and

@@ -21,8 +21,10 @@ This module owns the arena <-> kernel-plane packing:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.arena import UNEXPANDED, TreeArena
 from repro.kernels.search_wave import kernel as K
 from repro.kernels.search_wave import ref
@@ -113,6 +115,7 @@ def _resolve(sp, impl):
     return impl if impl is not None else sp.resolved_kernels
 
 
+@jax.named_scope(scopes.TREE)
 def tree_round(tree: TreeArena, domain, sp, lanes: int, valid, rng, *,
                impl=None, interpret=False):
     """One fused tree-parallel round.  Pallas path: launch 1 is
@@ -145,6 +148,7 @@ def tree_round(tree: TreeArena, domain, sp, lanes: int, valid, rng, *,
     return tree, sel
 
 
+@jax.named_scope(scopes.TREE)
 def pipeline_tick(tree: TreeArena, domain, sp, lanes: int, wave_valid,
                   buf_se, buf_ep, buf_pb, rng, *, impl=None,
                   interpret=False):

@@ -33,9 +33,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.arena import UNEXPANDED, TreeArena
 
 
+@jax.named_scope(scopes.TREE)
 def expand_wave_struct(tree: TreeArena, sp, sel):
     """Structural Expand for a whole wave: allocate rows + link children.
 
@@ -96,25 +98,29 @@ def expand_wave_struct(tree: TreeArena, sp, sel):
     return tree, es
 
 
+@jax.named_scope(scopes.TREE)
 def finish_expand(tree: TreeArena, domain, es):
     """Domain half of Expand (outside any kernel): vmap ``domain.step`` over
     the wave, scatter the new rows' state/terminal, and assemble the
     Expand->Playout buffer.  Shared by the ref and Pallas fused paths."""
-    parent_state = jax.tree_util.tree_map(
-        lambda x: x[es["leaf"]], tree.state)
-    child_state = jax.vmap(domain.step)(parent_state, es["slot"])
-    term = jax.vmap(domain.is_terminal)(child_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        parent_state = jax.tree_util.tree_map(
+            lambda x: x[es["leaf"]], tree.state)
+    with jax.named_scope(scopes.EXPAND):
+        child_state = jax.vmap(domain.step)(parent_state, es["slot"])
+        term = jax.vmap(domain.is_terminal)(child_state)
     can, new = es["can"], es["new"]
-    tree = tree.replace(
-        terminal=tree.terminal.at[new].set(term, mode="drop"),
-        state=jax.tree_util.tree_map(
-            lambda buf, s: buf.at[new].set(s, mode="drop"),
-            tree.state, child_state))
-    state = jax.tree_util.tree_map(
-        lambda s_par, s_ch: jnp.where(
-            jnp.reshape(can, can.shape + (1,) * (jnp.ndim(s_ch) - 1)),
-            s_ch, s_par),
-        parent_state, child_state)
+    with jax.named_scope(scopes.NODE_STATE):
+        tree = tree.replace(
+            state=jax.tree_util.tree_map(
+                lambda buf, s: buf.at[new].set(s, mode="drop"),
+                tree.state, child_state))
+        state = jax.tree_util.tree_map(
+            lambda s_par, s_ch: jnp.where(
+                jnp.reshape(can, can.shape + (1,) * (jnp.ndim(s_ch) - 1)),
+                s_ch, s_par),
+            parent_state, child_state)
+    tree = tree.replace(terminal=tree.terminal.at[new].set(term, mode="drop"))
     return tree, {"path": es["path"], "node": es["node"], "is_new": can,
                   "state": state, "valid": es["valid"]}
 

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core.stages import SearchParams
 from repro.core.tree import Tree, root_child_stats
 from repro.search.domain import Domain, missing_members
@@ -164,6 +165,7 @@ def _ensure_builtin_strategies() -> None:
 # ---------------------------------------------------------------------------
 # result assembly helper (used by strategies.py)
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.TREE)
 def make_stats(requested, completed, duplicates, ticks) -> Dict[str, jnp.ndarray]:
     completed = jnp.asarray(completed, jnp.int32)
     return {
@@ -175,6 +177,7 @@ def make_stats(requested, completed, duplicates, ticks) -> Dict[str, jnp.ndarray
     }
 
 
+@jax.named_scope(scopes.TREE)
 def result_from_tree(tree: Tree, stats: Dict[str, jnp.ndarray],
                      extras: Optional[Dict[str, Any]] = None) -> SearchResult:
     n, w, valid = root_child_stats(tree)

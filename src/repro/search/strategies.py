@@ -33,6 +33,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core import stages as S
 from repro.core.tree import init_tree, root_child_stats
 from repro.search.api import (SearchConfig, SearchResult, make_stats,
@@ -109,19 +110,22 @@ def leaf(domain, cfg: SearchConfig, rng) -> SearchResult:
     def it(tree, rng_t):
         tree, sel = S.select_one(tree, sp, jnp.asarray(True))
         tree, exp = S.expand_one(tree, domain, sp, sel)
-        values = jax.vmap(lambda r: domain.playout(exp["state"], r))(
-            jax.random.split(rng_t, workers))
-        v_sum = values.sum()
-        # aggregate backup: n += workers, w += sum(values) along the path;
-        # drain whichever in-flight plane Select/Expand incremented
-        paths = exp["path"]
-        mask = paths >= 0
-        idx = jnp.maximum(paths, 0)
-        infl = S.infl_plane(tree, sp).at[idx].add(-mask.astype(jnp.int32))
-        tree = tree.replace(
-            visits=tree.visits.at[idx].add(mask * workers),
-            value=tree.value.at[idx].add(jnp.where(mask, v_sum, 0.0)),
-            **{("unobs" if sp.wu else "vloss"): infl})
+        with jax.named_scope(scopes.PLAYOUT):
+            values = jax.vmap(lambda r: domain.playout(exp["state"], r))(
+                jax.random.split(rng_t, workers))
+        with jax.named_scope(scopes.TREE):
+            v_sum = values.sum()
+            # aggregate backup: n += workers, w += sum(values) along the
+            # path; drain whichever in-flight plane Select/Expand incremented
+            paths = exp["path"]
+            mask = paths >= 0
+            idx = jnp.maximum(paths, 0)
+            infl = S.infl_plane(tree, sp).at[idx].add(
+                -mask.astype(jnp.int32))
+            tree = tree.replace(
+                visits=tree.visits.at[idx].add(mask * workers),
+                value=tree.value.at[idx].add(jnp.where(mask, v_sum, 0.0)),
+                **{("unobs" if sp.wu else "vloss"): infl})
         return tree, sel["dup"]
 
     tree, dups = jax.lax.scan(it, tree, jax.random.split(rng, iters))
@@ -215,15 +219,17 @@ def pipeline(domain, cfg: SearchConfig, rng) -> SearchResult:
         }
         return (tree, new_se, new_ep, new_pb), st
 
-    rngs = jax.random.split(rng, n_ticks)
-    ts = jnp.arange(n_ticks)
+    with jax.named_scope(scopes.TREE):
+        rngs = jax.random.split(rng, n_ticks)
+        ts = jnp.arange(n_ticks)
     (tree, *_), st = jax.lax.scan(tick, init_carry, (ts, rngs))
-    stats = make_stats(n_waves * lanes, st["completed"].sum(),
-                       st["dup"].sum(), n_ticks)
-    extras = {
-        "mean_occupancy": st["occupancy"].mean() / PIPE_STAGES,
-        "dup_per_tick": st["dup"],
-        "dup_within": st["dup_within"].sum(),
-        "dup_cross": st["dup_cross"].sum(),
-    }
+    with jax.named_scope(scopes.TREE):
+        stats = make_stats(n_waves * lanes, st["completed"].sum(),
+                           st["dup"].sum(), n_ticks)
+        extras = {
+            "mean_occupancy": st["occupancy"].mean() / PIPE_STAGES,
+            "dup_per_tick": st["dup"],
+            "dup_within": st["dup_within"].sum(),
+            "dup_cross": st["dup_cross"].sum(),
+        }
     return result_from_tree(tree, stats, extras)

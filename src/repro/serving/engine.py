@@ -15,6 +15,14 @@ the lifecycle timings (queue wait, TTFT, per-token gaps, latency) and
 engine counters; ``run_until_drained`` returns its per-request summaries
 and ``ServingEngine.stats.snapshot()`` is a flat wandb-ready dict.
 
+Host spans (``jax.profiler.TraceAnnotation``; one inactive ``TraceMe`` each
+when no profiler runs) mark the engine's phases in a profile, on the device
+ops' clock: ``serving.step`` around ``step``, ``serving.admit`` around each
+admission or eviction (with the request's ``uid`` and ``slot``), and in
+search decoding ``serving.search`` (dispatch of the per-token program),
+``serving.sync`` (the one transfer of its tokens and root counters) and
+``serving.commit`` (the per-slot bookkeeping).
+
 Two per-slot decode modes (EngineConfig.decode):
 
 * ``"greedy"`` — KV-cached argmax decoding (the seed behaviour).
@@ -42,10 +50,12 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.base import ModelConfig, get_family
-from repro.serving.mcts_decode import (MCTSDecodeConfig, ReusableSearcher,
-                                       make_batched_searcher)
+from repro.serving.mcts_decode import (BatchedSearcher, MCTSDecodeConfig,
+                                       ReusableSearcher,
+                                       make_batched_searcher, unpack)
 from repro.serving.scheduler import (Admit, Evict, Request, RequestScheduler)
 from repro.serving.stats import ServingStats, percentile
 
@@ -139,11 +149,12 @@ class ServingEngine:
         — the prefix buffer row is zeroed and any searcher carry row goes
         stale (readmission overwrites it via ``admit``).  The request keeps
         its committed tokens; readmission re-prefills prompt + out_tokens."""
-        self.stats.on_preempt(req.uid, self.stats.now())
-        if self.mode == "mcts":
-            self.prefix_buf[i] = 0
-            self.prefix_len[i] = 0
-        # greedy: the KV row is dead weight until the slot is refilled
+        with TraceAnnotation("serving.admit", uid=req.uid, slot=i):
+            self.stats.on_preempt(req.uid, self.stats.now())
+            if self.mode == "mcts":
+                self.prefix_buf[i] = 0
+                self.prefix_len[i] = 0
+            # greedy: the KV row is dead weight until the slot is refilled
 
     def shrink(self, lost_slots) -> List[int]:
         """Elastic shrink event (DESIGN.md §13): a lost host's slots are
@@ -174,6 +185,10 @@ class ServingEngine:
         self.sched.retire(i)
 
     def _on_admit(self, i: int, req: Request):
+        with TraceAnnotation("serving.admit", uid=req.uid, slot=i):
+            self._admit(i, req)
+
+    def _admit(self, i: int, req: Request):
         self.stats.on_admit(req.uid, self.stats.now())
         if req.budget_left <= 0:
             # nothing to decode: finish without touching device state
@@ -225,18 +240,19 @@ class ServingEngine:
         """One decode step over all live slots.  Slots freed mid-step (EOS,
         budget, capacity) are refilled before returning, so the NEXT step
         already decodes the replacement — no idle step in between."""
-        self._admit_loop()
-        live = self.sched.live()
-        if not live:
-            return 0
-        if self.mode == "mcts":
-            emitted = self._mcts_step(live)
-            self.stats.on_step(emitted, searched=len(live))
-        else:
-            emitted = self._greedy_step(live)
-            self.stats.on_step(emitted)
-        self._admit_loop()          # refill freed slots in the same step
-        return emitted
+        with TraceAnnotation("serving.step"):
+            self._admit_loop()
+            live = self.sched.live()
+            if not live:
+                return 0
+            if self.mode == "mcts":
+                emitted = self._mcts_step(live)
+                self.stats.on_step(emitted, searched=len(live))
+            else:
+                emitted = self._greedy_step(live)
+                self.stats.on_step(emitted)
+            self._admit_loop()      # refill freed slots in the same step
+            return emitted
 
     def _greedy_step(self, live: List[int]) -> int:
         logits, self.cache = self._decode(self.params, self.cache,
@@ -255,34 +271,53 @@ class ServingEngine:
 
     def _mcts_step(self, live: List[int]) -> int:
         """One batched multi-root search over every slot; commit one token
-        per live slot.  Dead slots are searched too (the program is one fixed
-        [B]-batch) and their outputs ignored."""
-        self._rng, sub = jax.random.split(self._rng)
-        if self._carry is not None:
-            toks, self._carry = self._mcts_search.step(
-                self.prefix_buf, self.prefix_len, sub, self._carry)
-            toks = np.asarray(toks)
-        else:
-            toks = np.asarray(self._mcts_search(
-                jnp.asarray(self.prefix_buf), jnp.asarray(self.prefix_len),
-                sub))
-        now = self.stats.now()
-        for i in live:
-            req = self.sched.request(i)
-            tok = int(toks[i])
-            req.out_tokens.append(tok)
-            self.stats.on_token(req.uid, now)
-            at_capacity = self.prefix_len[i] >= self.ecfg.max_seq
-            if not at_capacity:
-                self.prefix_buf[i, self.prefix_len[i]] = tok
-                self.prefix_len[i] += 1
-            self.sched.on_token(i)
-            # finish at the sequence capacity too — further searches would
-            # keep emitting from the same frozen prefix
-            if (self.sched.exhausted(i) or tok == self.ecfg.eos_token
-                    or at_capacity):
-                self._finish(i, req)
+        per live slot, with the search root's counters.  Dead slots are
+        searched too (the program is one fixed [B]-batch) and their outputs
+        ignored."""
+        with TraceAnnotation("serving.search"):
+            self._rng, sub = jax.random.split(self._rng)
+            out, packed = self._dispatch(sub)
+        with TraceAnnotation("serving.sync"):
+            out = np.asarray(out)
+            toks, counters = unpack(out) if packed else (out, None)
+        with TraceAnnotation("serving.commit"):
+            now = self.stats.now()
+            for i in live:
+                req = self.sched.request(i)
+                tok = int(toks[i])
+                req.out_tokens.append(tok)
+                if counters is not None:
+                    req.root_visits.append(counters["root_visits"][i])
+                    req.root_values.append(counters["root_values"][i])
+                self.stats.on_token(req.uid, now)
+                at_capacity = self.prefix_len[i] >= self.ecfg.max_seq
+                if not at_capacity:
+                    self.prefix_buf[i, self.prefix_len[i]] = tok
+                    self.prefix_len[i] += 1
+                self.sched.on_token(i)
+                # finish at the sequence capacity too — further searches
+                # would keep emitting from the same frozen prefix
+                if (self.sched.exhausted(i) or tok == self.ecfg.eos_token
+                        or at_capacity):
+                    self._finish(i, req)
+            if counters is not None:
+                self.stats.on_search(
+                    int(counters["playouts"][live].sum()),
+                    int(counters["duplicates"][live].sum()))
         return len(live)
+
+    def _dispatch(self, rng):
+        """Launch the per-token program without waiting for it.  Returns its
+        one device output and whether that holds the root's counters beside
+        the tokens (``unpack``): a per-token callable other than the
+        searchers returns tokens alone."""
+        s, buf, lens = self._mcts_search, self.prefix_buf, self.prefix_len
+        if self._carry is not None:
+            out, self._carry = s.search(buf, lens, rng, self._carry)
+            return out, True
+        if isinstance(s, BatchedSearcher):
+            return s.search(buf, lens, rng), True
+        return s(jnp.asarray(buf), jnp.asarray(lens), rng), False
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[str, Any]:
         emitted = 0

@@ -48,12 +48,13 @@ scan = lane-major; DESIGN.md §11).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, List
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core.domains.lm_decode import CachedLMDecodeDomain, LMDecodeDomain
 from repro.core.tree import init_tree, reroot, reroot_ok
 from repro.models.base import ModelConfig, seq_prefill, seq_step
@@ -200,6 +201,9 @@ class ReusableSearcher:
         carry = s.admit(carry, slot, row, plen)  # request admitted: reset
                                                  # warm, prefill KV row once
         toks, carry = s.step(buf, lens, rng, carry)   # one token for all B
+        out, carry = s.search(buf, lens, rng, carry)  # the same, tokens
+                                                 # and root counters in one
+                                                 # array (``unpack``)
 
     ``admit`` is the ONLY place a prompt is prefilled; eviction needs no
     call (readmission overwrites the slot), which is exactly the eviction
@@ -283,6 +287,7 @@ class ReusableSearcher:
         local = jnp.where((local >= 0) & (local < rows), local, rows)
         return self._admit_impl(params, carry, local, buf_row, plen)
 
+    @jax.named_scope(scopes.ROOT)
     def _admit_impl(self, params, carry, slot, buf_row, plen):
         """Out-of-range ``slot`` rows are dropped (``_admit_local``)."""
         d = self.dcfg
@@ -311,8 +316,14 @@ class ReusableSearcher:
     def step(self, buf, lens, rng, carry):
         """One batched multi-root search over all slots -> each slot's
         chosen token, plus the carry advanced by the committed tokens."""
-        toks, carry = self._jstep(*self._args(buf, lens, rng, carry))
-        return toks[:self.batch], carry
+        out, carry = self.search(buf, lens, rng, carry)
+        return out[:self.batch, 0], carry
+
+    def search(self, buf, lens, rng, carry):
+        """``step``'s program as the device returns it: its one output (the
+        padded rows included), each slot's token beside its root counters
+        (``unpack``), and the advanced carry."""
+        return self._jstep(*self._args(buf, lens, rng, carry))
 
     def lower(self, buf, lens, rng, carry):
         """The per-token program ``step`` runs, lowered."""
@@ -327,40 +338,42 @@ class ReusableSearcher:
             # reroot every slot's arena on its committed action (recycling
             # the abandoned rows); a slot is reusable only if it is alive
             # AND the committed child was actually expanded last search
-            use = carry["alive"] & jax.vmap(reroot_ok)(
-                carry["arena"], carry["action"])
-            ar = jax.vmap(reroot)(carry["arena"], carry["action"])
+            with jax.named_scope(scopes.TREE):
+                use = carry["alive"] & jax.vmap(reroot_ok)(
+                    carry["arena"], carry["action"])
+                ar = jax.vmap(reroot)(carry["arena"], carry["action"])
         domains = []
         for i in range(rows):
-            kw = {}
-            if d.kv_splice:
-                kw["root_cache"] = jax.tree_util.tree_map(
-                    lambda x: x[i], carry["cache"])
-                kw["root_logits"] = carry["logits"][i]
-            dom = _domain(cfg, params, buf[i], d, prompt_len=lens[i], **kw)
+            with jax.named_scope(scopes.ROOT):
+                kw = {}
+                if d.kv_splice:
+                    kw["root_cache"] = jax.tree_util.tree_map(
+                        lambda x: x[i], carry["cache"])
+                    kw["root_logits"] = carry["logits"][i]
+                dom = _domain(cfg, params, buf[i], d, prompt_len=lens[i],
+                              **kw)
             if d.tree_reuse:
-                ar_i = jax.tree_util.tree_map(lambda x: x[i], ar)
-                # carried terminal flags reflect the PREVIOUS horizon
-                # (len >= plen + depth, and plen just advanced) — refresh
-                # them against this token's domain
-                ar_i = ar_i.replace(
-                    terminal=jax.vmap(dom.is_terminal)(ar_i.state))
+                with jax.named_scope(scopes.TREE):
+                    ar_i = jax.tree_util.tree_map(lambda x: x[i], ar)
+                    # carried terminal flags reflect the PREVIOUS horizon
+                    # (len >= plen + depth, and plen just advanced) —
+                    # refresh them against this token's domain
+                    ar_i = ar_i.replace(
+                        terminal=jax.vmap(dom.is_terminal)(ar_i.state))
                 dom = dataclasses.replace(
                     dom, root_arena=ar_i, root_arena_alive=use[i])
             domains.append(dom)
         res = search_keys(domains, self.scfg, keys)
         if d.kv_splice:
             # the carried logits ARE the root's next-token distribution
-            tops = jax.vmap(
-                lambda lg: jax.lax.top_k(lg, d.num_actions)[1])(
-                carry["logits"])
+            with jax.named_scope(scopes.TOPK):
+                tops = jax.vmap(
+                    lambda lg: jax.lax.top_k(lg, d.num_actions)[1])(
+                    carry["logits"])
         else:
-            def root_topk(buf_row, len_row):
-                dom = _domain(cfg, params, buf_row, d, prompt_len=len_row)
-                _, top = dom._topk(dom.root_state())
-                return top
-            tops = jax.vmap(root_topk)(buf, lens)
-        toks = tops[jnp.arange(rows), res.best_action].astype(jnp.int32)
+            tops = jax.vmap(lambda b, n: _root_topk(cfg, params, b, d, n))(
+                buf, lens)
+        toks = _pick(tops, res.best_action)
         new = dict(carry)
         if d.tree_reuse:
             # the searched arenas + committed actions ARE the carry; the
@@ -371,11 +384,58 @@ class ReusableSearcher:
         if d.kv_splice:
             # advance each root row by the committed token (ONE step, vs a
             # whole-prefix prefill on the cold path)
-            logits, cache = jax.vmap(
-                lambda c, t, p: seq_step(cfg, params, c, t, p))(
-                carry["cache"], toks, lens)
+            with jax.named_scope(scopes.ROOT):
+                logits, cache = jax.vmap(
+                    lambda c, t, p: seq_step(cfg, params, c, t, p))(
+                    carry["cache"], toks, lens)
             new["cache"], new["logits"] = cache, logits
-        return toks, new
+        return _outputs(toks, res), new
+
+
+@jax.named_scope(scopes.ROOT)
+def _root_topk(cfg: ModelConfig, params, buf_row, dcfg: MCTSDecodeConfig,
+               len_row):
+    """The root's top-A tokens, from its own prefill."""
+    dom = _domain(cfg, params, buf_row, dcfg, prompt_len=len_row)
+    _, top = dom._topk(dom.root_state())
+    return top
+
+
+@jax.named_scope(scopes.ROOT)
+def _pick(tops, best_action):
+    """Each slot's committed token: its root's top-A entry at the
+    search's pick."""
+    rows = tops.shape[0]
+    return tops[jnp.arange(rows), best_action].astype(jnp.int32)
+
+
+@jax.named_scope(scopes.TREE)
+def _outputs(toks, res):
+    """The per-token program's one output, ``[rows, 3 + 2A]`` i32: each
+    slot's token, playouts completed and duplicates (lanes whose leaf
+    already had playouts in flight), then its root's visits and the bits of
+    its mean values W/N (f32, 0 where N = 0) per action.  One array, so
+    that the host fetches tokens and counters in one transfer; read off the
+    finished search's root, so they cost no device work of their own."""
+    n = res.action_visits
+    mean = jnp.where(n > 0, res.action_value
+                     / jnp.maximum(n, 1).astype(jnp.float32), 0.0)
+    return jnp.concatenate(
+        [toks[:, None], res.stats["playouts_completed"][:, None],
+         res.stats["duplicates"][:, None], n.astype(jnp.int32),
+         jax.lax.bitcast_convert_type(mean, jnp.int32)], axis=1)
+
+
+def unpack(out) -> Tuple[np.ndarray, Dict[str, np.ndarray]]:
+    """A per-token program's output, fetched, as ``(tokens [B],
+    counters)``: ``root_visits`` ``[B, A]`` i32, ``root_values`` ``[B, A]``
+    f32, ``playouts`` and ``duplicates`` ``[B]`` i32."""
+    out = np.asarray(out)
+    a = (out.shape[1] - 3) // 2
+    return out[:, 0], {
+        "root_visits": out[:, 3:3 + a],
+        "root_values": np.ascontiguousarray(out[:, 3 + a:]).view(np.float32),
+        "playouts": out[:, 1], "duplicates": out[:, 2]}
 
 
 def _place_weights(params, mesh):
@@ -405,7 +465,9 @@ def _pad_slots(buf, lens, extra: int):
 class BatchedSearcher:
     """The stateless per-token searcher: ``(token_buf [B, buf_len] i32,
     lens [B] i32, rng) -> [B] i32``, one jitted device program that takes
-    the weights as an argument.  ``lower`` exposes that program."""
+    the weights as an argument.  ``search`` returns that program's output,
+    each slot's token beside its root counters (``unpack``); ``lower``
+    exposes the program."""
 
     def __init__(self, jstep, params, batch: int, padded: int):
         self._jstep, self.params = jstep, params
@@ -416,7 +478,13 @@ class BatchedSearcher:
         return self.params, buf, lens, jax.random.split(rng, self.padded)
 
     def __call__(self, buf, lens, rng):
-        return self._jstep(*self._args(buf, lens, rng))[:self.batch]
+        return self.search(buf, lens, rng)[:self.batch, 0]
+
+    def search(self, buf, lens, rng):
+        """The per-token program's one output as the device holds it (the
+        padded rows included): each slot's token beside its root counters
+        (``unpack``)."""
+        return self._jstep(*self._args(buf, lens, rng))
 
     def lower(self, buf, lens, rng):
         return self._jstep.lower(*self._args(buf, lens, rng))
@@ -454,17 +522,14 @@ def make_batched_searcher(cfg: ModelConfig, params, dcfg: MCTSDecodeConfig,
 
     def step(params, buf, lens, keys):
         """Per-slot keys; under a mesh each device runs its own slots."""
-        def root_topk(buf_row, len_row):
-            d = _domain(cfg, params, buf_row, dcfg, prompt_len=len_row)
-            _, top = d._topk(d.root_state())
-            return top
-
         rows = buf.shape[0]
-        domains = [_domain(cfg, params, buf[i], dcfg, prompt_len=lens[i])
-                   for i in range(rows)]
+        with jax.named_scope(scopes.ROOT):
+            domains = [_domain(cfg, params, buf[i], dcfg, prompt_len=lens[i])
+                       for i in range(rows)]
         res = search_keys(domains, scfg, keys)
-        tops = jax.vmap(root_topk)(buf, lens)            # [rows, A], one pass
-        return tops[jnp.arange(rows), res.best_action].astype(jnp.int32)
+        tops = jax.vmap(lambda b, n: _root_topk(cfg, params, b, dcfg, n))(
+            buf, lens)                                   # [rows, A], one pass
+        return _outputs(_pick(tops, res.best_action), res)
 
     if mesh is None:
         jstep = jax.jit(step)

@@ -48,7 +48,10 @@ POLICIES = ("fcfs", "spf")
 class Request:
     """One decode request.  ``priority`` orders admission and drives
     preemption (higher = more important; default 0).  ``enqueue_t`` /
-    ``finish_t`` are populated by the engine from its stats clock."""
+    ``finish_t`` are populated by the engine from its stats clock.  Under
+    search decoding each committed token also leaves the search root's
+    visits ``[A]`` and mean values ``[A]`` per action, one entry per token
+    in ``root_visits`` / ``root_values`` (empty under greedy decoding)."""
     uid: int
     prompt: np.ndarray                 # [len] int32
     max_new_tokens: int = 16
@@ -57,6 +60,8 @@ class Request:
     done: bool = False
     enqueue_t: float = 0.0
     finish_t: float = 0.0
+    root_visits: List[np.ndarray] = dataclasses.field(default_factory=list)
+    root_values: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     @property
     def prefix_len(self) -> int:

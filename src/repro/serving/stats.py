@@ -17,8 +17,10 @@ Tracked per request (keyed by ``Request.uid``):
 * ``preemptions`` — times the request was evicted and requeued
 
 Engine-level: requests submitted/admitted/finished, preemption events,
-tokens, steps, wall tokens/s.  Distributions keep a bounded sample list and
-report nearest-rank p50/p95.
+tokens, steps, wall tokens/s, and under search decoding the playouts
+completed and duplicates of the searches that committed tokens.
+Distributions keep the most recent samples in a bounded ring and report
+nearest-rank p50/p95 over them.
 
 All timestamps come from one injectable monotonic ``clock`` so latencies
 are well defined; tests may pass a fake clock for determinism.
@@ -41,8 +43,9 @@ def percentile(xs: List[float], q: float) -> float:
 
 class Series:
     """Bounded sample series: count/sum always exact, percentiles over the
-    first ``max_samples`` observations (enough for serving dashboards; exact
-    in every test-sized run)."""
+    most recent ``max_samples`` observations, kept in a ring, so that a
+    server running for hours reports its current tail (exact in every
+    test-sized run)."""
 
     def __init__(self, max_samples: int = 4096):
         self.max_samples = max_samples
@@ -51,10 +54,12 @@ class Series:
         self.total = 0.0
 
     def add(self, v: float) -> None:
-        self.count += 1
-        self.total += float(v)
         if len(self.samples) < self.max_samples:
             self.samples.append(float(v))
+        else:                       # overwrite the oldest
+            self.samples[self.count % self.max_samples] = float(v)
+        self.count += 1
+        self.total += float(v)
 
     @property
     def mean(self) -> float:
@@ -113,6 +118,8 @@ class ServingStats:
         self.tokens = 0
         self.steps = 0
         self.searches = 0
+        self.playouts = 0
+        self.duplicates = 0
         self._t0: Optional[float] = None
         self._t_last: Optional[float] = None
 
@@ -163,6 +170,12 @@ class ServingStats:
         self.steps += 1
         self.searches += searched
 
+    def on_search(self, playouts: int, duplicates: int) -> None:
+        """Playouts completed and duplicates of one step's searches, over
+        the slots that committed a token."""
+        self.playouts += playouts
+        self.duplicates += duplicates
+
     # -- reporting ----------------------------------------------------------
     def request_summaries(self) -> Dict[int, Dict[str, Any]]:
         return {uid: r.summary() for uid, r in self.requests.items()}
@@ -177,6 +190,8 @@ class ServingStats:
             "serving/tokens": float(self.tokens),
             "serving/steps": float(self.steps),
             "serving/searches": float(self.searches),
+            "serving/playouts": float(self.playouts),
+            "serving/duplicates": float(self.duplicates),
         }
         if self._t0 is not None and self._t_last is not None:
             wall = self._t_last - self._t0

@@ -174,6 +174,34 @@ def test_cached_parity_subprocess_8dev():
         u = mcts_decode_batch(CFG, params, rg, 1,
                               dataclasses.replace(dcfg, cached=False))
         assert c == u, (c, u)
+        # the root counters leave the meshed program beside the tokens,
+        # sharded like them, equal to the unmeshed program's
+        from repro.serving import make_batched_searcher
+        from repro.serving.mcts_decode import unpack
+        lens = np.full((8,), 3, np.int32)
+        key = jax.random.key(3)
+        outs = [make_batched_searcher(CFG, params, dcfg, batch=8,
+                                      mesh=m).search(eq, lens, key)
+                for m in (None, False)]
+        assert len(outs[0].sharding.device_set) == 8
+        (tm, cm), (t1, c1) = [unpack(o) for o in jax.device_get(outs)]
+        assert (tm == t1).all()
+        for k in ("root_visits", "playouts", "duplicates"):
+            assert (cm[k] == c1[k]).all(), k
+        # one row per device against eight vmapped rows: float rounding
+        np.testing.assert_allclose(cm["root_values"], c1["root_values"],
+                                   rtol=1e-5)
+        assert (cm["playouts"] == dcfg.budget).all()
+        assert (cm["root_visits"].sum(-1) <= cm["playouts"]).all()
+        st = make_batched_searcher(
+            CFG, params, dataclasses.replace(dcfg, kv_splice=True), batch=8)
+        carry = st.init_carry(eq.shape[1])
+        for i in range(8):
+            carry = st.admit(carry, i, eq[i], 3)
+        out, _ = st.search(eq, lens, key, carry)
+        assert len(out.sharding.device_set) == 8
+        assert (unpack(jax.device_get(out))[1]["playouts"]
+                == dcfg.budget).all()
         print("OK")
     """)
     r = subprocess.run(

@@ -199,6 +199,24 @@ def test_series_summary():
     assert Series().summary("y") == {}
 
 
+def test_series_percentiles_follow_the_latest_samples():
+    """Past ``max_samples`` the ring drops the oldest: after 5,000 gaps of
+    which the last 4,096 are slow, p95 and p50 read the slow ones, while
+    count and mean stay exact over all 5,000."""
+    t = [0.0]
+    stats = ServingStats(clock=lambda: t[0])
+    stats.on_submit(0, stats.now())
+    stats.on_token(0, stats.now())
+    for i in range(5000):
+        t[0] += 0.01 if i < 5000 - 4096 else 1.0
+        stats.on_token(0, stats.now())
+    s = stats.token_latency
+    assert s.count == 5000 and len(s.samples) == 4096
+    assert s.mean == pytest.approx((904 * 0.01 + 4096 * 1.0) / 5000)
+    assert s.p(95) == pytest.approx(1.0) and s.p(50) == pytest.approx(1.0)
+    assert stats.snapshot()["serving/token_latency_p95"] == pytest.approx(1.0)
+
+
 def test_stats_lifecycle_with_fake_clock():
     t = [0.0]
     stats = ServingStats(clock=lambda: t[0])

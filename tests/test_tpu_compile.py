@@ -12,6 +12,7 @@ imported: only the test worker that runs this file loads the TPU library.
 It skips when the topology cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -184,3 +185,19 @@ def test_serving_step_compiles(one_chip, stateful):
     compiled = s._jstep.lower(*args).compile()
     assert _kernel_ops(compiled, "search_wave") > 0
     assert _kernel_ops(compiled, "decode_attention") > 0
+    # every stage scope survives the chip's compiler, and each kernel call
+    # keeps its own name as the innermost component of its op_name
+    from repro.core import scopes
+    hlo = compiled.as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert set(scopes.STAGES) <= {scopes.stage_of(o) for o in op_names}
+    wrappers = {"pallas_call", "closed_call", "while", "body", "cond",
+                "checkpoint"}
+    kernels = set()
+    for ln in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in ln:
+            o = re.search(r'op_name="([^"]*)"', ln).group(1)
+            kernels.add([c for c in o.split("/") if c not in wrappers
+                         and not c.startswith(("jit(", "vmap("))][-1])
+    assert {"search_wave_bes", "decode_attention"} <= kernels
+    assert not {scopes.stage_of(k) for k in kernels} - {None}
