@@ -25,6 +25,7 @@ balancing.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -32,6 +33,31 @@ import jax.numpy as jnp
 
 from repro.core import scopes
 from repro.models.base import ModelConfig, get_family, seq_prefill, seq_step
+
+
+def vocab_top_k(logits, k: int):
+    """``jax.lax.top_k(logits, k)`` over the last axis, as ``(values,
+    indices)``: the same values and indices, ties included."""
+    return _folded_top_k(k)(logits)
+
+
+@functools.cache
+def _folded_top_k(k: int):
+    # The TPU compiler lowers a rank-2 top_k to its TopK op but a rank-3
+    # one (the search vmaps the top-A over lanes and slots) to a full sort
+    # of the vocabulary, so every vmap folds its axis into the rows.
+    @jax.custom_batching.custom_vmap
+    def top(x):
+        return tuple(jax.lax.top_k(x, k))
+
+    @top.def_vmap
+    def _fold(axis_size, in_batched, x):
+        # one argument: vmap calls the rule only where x is batched
+        vals, idx = top(x.reshape(-1, x.shape[-1]))
+        lead = x.shape[:-1]
+        return (vals.reshape(*lead, k), idx.reshape(*lead, k)), (True, True)
+
+    return top
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,7 +113,7 @@ class LMDecodeDomain:
     def _topk(self, state):
         logits = self._last_logits(state["toks"], state["len"])
         with jax.named_scope(scopes.TOPK):
-            return jax.lax.top_k(logits, self.num_actions)
+            return vocab_top_k(logits, self.num_actions)
 
     # -- domain API ----------------------------------------------------------
     def step(self, state, action):
@@ -166,7 +192,7 @@ class CachedLMDecodeDomain(LMDecodeDomain):
 
     @jax.named_scope(scopes.TOPK)
     def _topk(self, state):
-        return jax.lax.top_k(self._state_logits(state), self.num_actions)
+        return vocab_top_k(self._state_logits(state), self.num_actions)
 
     # -- domain API ----------------------------------------------------------
     def step(self, state, action):

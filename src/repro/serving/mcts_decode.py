@@ -55,7 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scopes
-from repro.core.domains.lm_decode import CachedLMDecodeDomain, LMDecodeDomain
+from repro.core.domains.lm_decode import (CachedLMDecodeDomain, LMDecodeDomain,
+                                          vocab_top_k)
 from repro.core.tree import init_tree, reroot, reroot_ok
 from repro.models.base import ModelConfig, seq_prefill, seq_step
 from repro.parallel.compat import (batch_sharding, global_batch_put,
@@ -367,9 +368,7 @@ class ReusableSearcher:
         if d.kv_splice:
             # the carried logits ARE the root's next-token distribution
             with jax.named_scope(scopes.TOPK):
-                tops = jax.vmap(
-                    lambda lg: jax.lax.top_k(lg, d.num_actions)[1])(
-                    carry["logits"])
+                _, tops = vocab_top_k(carry["logits"], d.num_actions)
         else:
             tops = jax.vmap(lambda b, n: _root_topk(cfg, params, b, d, n))(
                 buf, lens)

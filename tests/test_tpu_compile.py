@@ -19,12 +19,14 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
+from repro.core import scopes
 from repro.core.domains.pgame import PGameDomain
 from repro.search import SearchConfig, SearchParams, search
 
 DOM = PGameDomain(num_actions=4, game_depth=8, binary_reward=False, seed=3)
 LANES = 8
 SMOLLM = get_config("smollm-135m")
+QWEN2 = get_config("qwen2-0.5b")
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +62,24 @@ def _sds(shape, dtype, sharding):
 def _kernel_ops(compiled, name):
     return sum(name in ln and "tpu_custom_call" in ln
                for ln in compiled.as_text().splitlines())
+
+
+def _assert_top_k_op(hlo, vocab):
+    """No sort over the vocabulary: every top-A over it is the chip's
+    ``TopK`` op, and each such op keeps the ``search.topk`` scope."""
+    wide = [ln for ln in hlo.splitlines()
+            if " sort(" in ln and _sorted_width(ln) == vocab]
+    assert not wide, wide[:2]
+    topk = [re.search(r'op_name="([^"]*)"', ln).group(1)
+            for ln in hlo.splitlines() if 'custom_call_target="TopK"' in ln]
+    assert topk and all(scopes.stage_of(o) == scopes.TOPK for o in topk)
+
+
+def _sorted_width(sort_line):
+    """The width of the dimension a sort instruction sorts."""
+    dims = re.search(r"\[([\d,]*)\]", sort_line).group(1).split(",")
+    axis = int(re.search(r"dimensions=\{(\d+)\}", sort_line).group(1))
+    return int(dims[axis])
 
 
 @pytest.mark.parametrize("method,level_assign,vl_mode,puct,roots,lanes", [
@@ -185,10 +205,10 @@ def test_serving_step_compiles(one_chip, stateful):
     compiled = s._jstep.lower(*args).compile()
     assert _kernel_ops(compiled, "search_wave") > 0
     assert _kernel_ops(compiled, "decode_attention") > 0
+    hlo = compiled.as_text()
+    _assert_top_k_op(hlo, cfg.vocab_size)
     # every stage scope survives the chip's compiler, and each kernel call
     # keeps its own name as the innermost component of its op_name
-    from repro.core import scopes
-    hlo = compiled.as_text()
     op_names = re.findall(r'op_name="([^"]*)"', hlo)
     assert set(scopes.STAGES) <= {scopes.stage_of(o) for o in op_names}
     wrappers = {"pallas_call", "closed_call", "while", "body", "cond",
@@ -201,3 +221,16 @@ def test_serving_step_compiles(one_chip, stateful):
                          and not c.startswith(("jit(", "vmap("))][-1])
     assert {"search_wave_bes", "decode_attention"} <= kernels
     assert not {scopes.stage_of(k) for k in kernels} - {None}
+
+
+def test_vocab_top_k_at_qwen2_vocabulary(one_chip):
+    """The cached domain's top-A over qwen2-0.5b's 151,936 tokens, vmapped
+    over slots and lanes as the search runs it: the chip's ``TopK`` op, not
+    a full sort of the vocabulary."""
+    from repro.core.domains.lm_decode import CachedLMDecodeDomain
+    dom = CachedLMDecodeDomain(cfg=QWEN2, params=None,
+                               prompt=jnp.zeros((8,), jnp.int32))
+    fn = jax.jit(jax.vmap(jax.vmap(lambda lg: dom._topk({"logits": lg}))))
+    compiled = fn.lower(_sds((4, 4, QWEN2.vocab_size), jnp.bfloat16,
+                             one_chip)).compile()
+    _assert_top_k_op(compiled.as_text(), QWEN2.vocab_size)
